@@ -67,14 +67,14 @@ def _sweep_point(a8, b8, want, border: int | None, variant: str, engine: str) ->
         t = pick_tiles(m, n, k, variant="lowrank")
         bm, bn, bk = t.bm, t.bn, t.bk
         u, v = lut_factors(border, RANK, engine)
-        fn = lambda x, y: amr_matmul_int8(x, y, u, v, bm=bm, bn=bn, bk=bk)  # noqa: E731
+        fn = lambda x, y: amr_matmul_int8(x, y, u, v)  # noqa: E731  (same tiles)
         got = np.asarray(fn(a8, b8)).astype(np.float64)
         us = _time(fn, a8, b8)
     elif variant == "lut":
         t = pick_tiles(m, n, k, variant="lut")
         bm, bn, bk = t.bm, t.bn, t.bk
         table = lut_lib.table_array(border, engine)
-        fn = lambda x, y: amr_matmul_int8_lut(x, y, table, bm=bm, bn=bn, bk=bk)  # noqa: E731
+        fn = lambda x, y: amr_matmul_int8_lut(x, y, table)  # noqa: E731  (same tiles)
         got = np.asarray(fn(a8, b8)).astype(np.float64)
         us = _time(fn, a8, b8)
     else:

@@ -3,29 +3,41 @@
 The paper's multiplier, as deployed on TPU (DESIGN.md §2 L2): for int8
 operands the approximate product is exactly ``a*b + E(a,b)`` with E the
 256x256 error table of the bit-accurate 2-digit AMR-MUL.  Two kernel
-variants trade fidelity against the unit they load:
+variants trade fidelity against the work they put on the MXU:
 
-**Low-rank (MXU)** — E factors as ``E ~= U V^T`` (SVD, rank r), so a block
-matmul becomes
+**Low-rank** — E factors as ``E ~= U V^T`` (SVD, rank r), so with the
+augmented factors ``U' = [a | U]`` and ``V' = [b | V]`` (the exact product
+rides as lane 0) a block is one contraction over K*(1+r) lanes:
 
-    acc += concat([A_f32, U[A+128]]) @ concat([B_f32, V[B+128]])
+    acc += sum_k U'[a_k]^T V'[b_k]
 
-— ONE (bm, bk*(1+r)) x (bk*(1+r), bn) MXU dot per block instead of per-
-element gather emulation on the VPU. U/V live whole in VMEM (256*r*4B).
 Per-product error vs the full table is bounded by the first dropped
 singular value ``sigma_{r+1}`` (see core/lut.py), i.e. <= K*sigma_{r+1}
 per output element.
 
-**Full-LUT (gather)** — the whole 256x256 int32 product table lives in
-VMEM (256KB) and each K step gathers the (bm, bn) outer-product block
-``LUT[a_k + 128, b_k + 128]`` from the flattened table, accumulating in
-int32.  Bit-exact by construction (zero error vs the schedule engine's
-replay — asserted in tests/test_kernels.py), VPU/gather-bound, so the
-shared tiling table (tiling.py) gives it narrower K tiles on accelerators.
+**Full-LUT** — bit-exact: the whole 256x256 product table sits in VMEM and
+each K step adds the (bm, bn) block ``LUT[a_k, b_k]`` into an int32
+accumulator.  Zero error vs the schedule engine's replay (asserted in
+tests/test_kernels.py).
+
+Neither variant gathers: the TPU compiler (Mosaic) lowers no general
+gather.  Both look up table rows with one-hot matmuls instead.  Row k of a
+block's operand indices becomes a (256, w) one-hot (value on sublanes, the
+block's rows or columns on lanes), and ``table^T @ one_hot`` picks the
+table entries on the MXU.  A one-hot product has one nonzero term, so the
+lookup is exact when the table is exact in bf16: the factors ride as three
+bf16 planes that sum back to the f32 value exactly, the LUT as two byte
+planes.  The low-rank contraction of the looked-up f32 values does not
+rely on the precision Mosaic gives an f32 dot: it splits both sides into
+three bf16 planes again and sums the six plane products an f32 dot keeps.  The A operand arrives transposed, (K, M), so that its one-hot rows
+are sublane slices too, and the final contraction runs over the leading
+axis of both sides.
 
 Tiling (both variants): grid (M/bm, N/bn, K/bk), K innermost so the
-accumulator scratch carries across the K sweep; block dims come from the
-shared ``tiling.AUTOTUNE`` table keyed on backend, clamped to divisors.
+accumulator scratch carries across the K sweep.  The jitted entry points
+pad M and N up to the block dims (padded rows/columns are sliced off); K
+is never padded, since AMR(0, b) need not be 0, so bk divides K.  Block
+dims come from ``tiling.pick_tiles``.
 
 ``interpret=None`` (default) autodetects per backend — compiled Mosaic on
 real TPU, interpreter mode on CPU and GPU (the kernels use pltpu memory
@@ -38,13 +50,78 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pallas_config import resolve_interpret
 
+from .tiling import pick_tiles
 
-def _amr_matmul_kernel(a_ref, b_ref, u_ref, v_ref, out_ref, acc_ref, *, n_k: int):
+_VALUES = 256            # int8 operand values; value + 128 indexes the tables
+_LEADING = (((0,), (0,)), ((), ()))   # dot_general: contract axis 0 of both
+_LUT_PLANE_LIMIT = 1 << 15            # byte planes are bf16-exact below this
+
+
+def _one_hot_row(idx_ref, k):
+    """(256, w) bf16 one-hot of row ``k`` of an int32 index scratch (w lanes):
+    entry [t, j] is 1 where idx[k, j] == t."""
+    row = idx_ref[pl.ds(k, 1), :]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (_VALUES, row.shape[1]), 0)
+    return (iota == row).astype(jnp.bfloat16)
+
+
+def _load_indices(ia_ref, ib_ref, at, b):
+    """Block operands (int8, values) -> int32 table indices in scratch."""
+    ia_ref[...] = at.astype(jnp.int32) + 128
+    ib_ref[...] = b.astype(jnp.int32) + 128
+
+
+def _pad_operands(a, b, bm: int, bn: int):
+    """A (..., M, K) -> A^T (..., K, Mp) and B (..., K, N) -> (..., K, Np),
+    with Mp / Np the next multiples of bm / bn (zero padding)."""
+    m, n = a.shape[-2], b.shape[-1]
+    at = jnp.swapaxes(a, -1, -2)
+    lead = [(0, 0)] * (at.ndim - 1)
+    return (jnp.pad(at, lead + [(0, -m % bm)]),
+            jnp.pad(b, lead + [(0, -n % bn)]))
+
+
+def _top_bf16(x):
+    """x (f32) cut to its top 16 bits: a bf16 value, exactly, in f32.  Bit
+    masking, not a round trip through bf16: on TPU, XLA drops an
+    f32 -> bf16 -> f32 round trip as excess precision, which made the
+    lower planes 0."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _split3(x):
+    """f32 -> three bf16 parts whose f32 sum, taken as (p0 + p1) + p2, is x
+    exactly (8 + 8 + 8 significand bits; each subtraction is exact)."""
+    hi = _top_bf16(x)
+    rest = x - hi
+    mid = _top_bf16(rest)
+    return (hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16),
+            (rest - mid).astype(jnp.bfloat16))
+
+
+# (i, j) plane pairs of a product of two 3-way splits down to 2**-24
+# relative, smallest terms first: the products an f32 dot keeps
+_F32_PAIRS = ((1, 1), (2, 0), (0, 2), (1, 0), (0, 1), (0, 0))
+
+
+def _augmented_planes(f):
+    """(256, r) factors -> bf16 planes of [value | f | 0]^T, rows padded to
+    a multiple of 8 (zero rows add nothing to the contraction)."""
+    values = jnp.arange(-128, 128, dtype=jnp.float32)[:, None]
+    aug = jnp.concatenate([values, f.astype(jnp.float32)], axis=1)
+    aug = jnp.pad(aug, [(0, 0), (0, -aug.shape[1] % 8)])
+    return jnp.concatenate(_split3(aug.T), axis=0)
+
+
+def _lowrank_kernel(at_ref, b_ref, ut_ref, vt_ref, out_ref,
+                    ia_ref, ib_ref, ua_ref, vb_ref, acc_ref, *, n_k: int):
     """One (bm, bn) output block; K swept by the innermost grid dim."""
     k_idx = pl.program_id(2)
 
@@ -52,29 +129,31 @@ def _amr_matmul_kernel(a_ref, b_ref, u_ref, v_ref, out_ref, acc_ref, *, n_k: int
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]                                  # (bm, bk) int8
-    b = b_ref[...]                                  # (bk, bn) int8
-    u = u_ref[...]                                  # (256, r) f32
-    v = v_ref[...]                                  # (256, r) f32
-    bm, bk = a.shape
-    bn = b.shape[1]
-    r = u.shape[1]
+    _load_indices(ia_ref, ib_ref, at_ref[...], b_ref[...])
+    ut = ut_ref[...]                                # (3R, 256) bf16
+    vt = vt_ref[...]
+    rows = ut.shape[0] // 3
 
-    ia = (a.astype(jnp.int32) + 128)
-    ib = (b.astype(jnp.int32) + 128)
-    ua = jnp.take(u, ia.reshape(-1), axis=0).reshape(bm, bk, r)
-    vb = jnp.take(v, ib.reshape(-1), axis=0).reshape(bk, bn, r)
+    def lookup(planes, idx_ref, k):                 # -> (R, w) f32, exact
+        got = jnp.dot(planes, _one_hot_row(idx_ref, k),
+                      preferred_element_type=jnp.float32)
+        return (got[:rows] + got[rows:2 * rows]) + got[2 * rows:]
 
-    # augmented operands: exact lane + r error lanes -> single MXU dot.
-    # lane order along the contraction axis is (k, [exact, err_1..err_r])
-    # on BOTH sides: A flattens (bm, bk, 1+r) -> (bm, bk*(1+r)); B must put
-    # the lane axis BEFORE bn: (bk, 1+r, bn) -> (bk*(1+r), bn).
-    a_aug = jnp.concatenate(
-        [a.astype(jnp.float32)[:, :, None], ua], axis=2).reshape(bm, bk * (1 + r))
-    b_aug = jnp.concatenate(
-        [b.astype(jnp.float32)[:, None, :], vb.transpose(0, 2, 1)],
-        axis=1).reshape(bk * (1 + r), bn)
-    acc_ref[...] += jnp.dot(a_aug, b_aug, preferred_element_type=jnp.float32)
+    def body(k, carry):
+        off = pl.multiple_of(k * rows, 8)
+        ua_ref[pl.ds(off, rows), :] = lookup(ut, ia_ref, k)
+        vb_ref[pl.ds(off, rows), :] = lookup(vt, ib_ref, k)
+        return carry
+
+    jax.lax.fori_loop(0, ia_ref.shape[0], body, 0)
+    # rows of ua/vb run (k, lane): one (bk*R)-deep contraction per block,
+    # f32-accurate from bf16 dots
+    ua, vb = _split3(ua_ref[...]), _split3(vb_ref[...])
+    acc = acc_ref[...]
+    for i, j in _F32_PAIRS:
+        acc = acc + jax.lax.dot_general(ua[i], vb[j], _LEADING,
+                                        preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
     @pl.when(k_idx == n_k - 1)
     def _store():
@@ -85,65 +164,107 @@ def _amr_matmul_kernel(a_ref, b_ref, u_ref, v_ref, out_ref, acc_ref, *, n_k: int
 def _amr_matmul_int8_jit(a, b, u, v, *, bm, bn, bk, interpret):
     M, K = a.shape
     N = b.shape[1]
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    assert K % bk == 0, (K, bk)
+    at, bp = _pad_operands(a, b, bm, bn)
+    ut, vt = _augmented_planes(u), _augmented_planes(v)
+    rows = ut.shape[0] // 3
     n_k = K // bk
-    grid = (M // bm, N // bn, n_k)
-    return pl.pallas_call(
-        functools.partial(_amr_matmul_kernel, n_k=n_k),
+    grid = (at.shape[1] // bm, bp.shape[1] // bn, n_k)
+    out = pl.pallas_call(
+        functools.partial(_lowrank_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec(u.shape, lambda i, j, k: (0, 0)),  # whole factors in VMEM
-            pl.BlockSpec(v.shape, lambda i, j, k: (0, 0)),
+            pl.BlockSpec(ut.shape, lambda i, j, k: (0, 0)),  # whole factors
+            pl.BlockSpec(vt.shape, lambda i, j, k: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((at.shape[1], bp.shape[1]), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bk, bm), jnp.int32),
+                        pltpu.VMEM((bk, bn), jnp.int32),
+                        pltpu.VMEM((bk * rows, bm), jnp.float32),
+                        pltpu.VMEM((bk * rows, bn), jnp.float32),
+                        pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(a, b, u, v)
+    )(at, bp, ut, vt)
+    return out[:M, :N]
 
 
 def amr_matmul_int8(a: jnp.ndarray, b: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
-                    *, bm: int = 128, bn: int = 128, bk: int = 128,
+                    *, bm: int | None = None, bn: int | None = None,
+                    bk: int | None = None,
                     interpret: bool | None = None) -> jnp.ndarray:
     """a (M,K) int8, b (K,N) int8, u/v (256,r) f32 -> (M,N) f32 approx products.
 
-    ``interpret=None`` resolves via pallas_config (env override / backend
-    autodetect) BEFORE the jitted inner function, so the jit cache is always
-    keyed on a concrete bool."""
-    return _amr_matmul_int8_jit(a, b, u, v, bm=bm, bn=bn, bk=bk,
+    Tiles left ``None`` come from ``pick_tiles``.  ``interpret=None``
+    resolves via pallas_config (env override / backend autodetect) BEFORE
+    the jitted inner function, so the jit cache is always keyed on a
+    concrete bool."""
+    t = pick_tiles(a.shape[0], b.shape[1], a.shape[1], variant="lowrank",
+                   bm=bm, bn=bn, bk=bk)
+    return _amr_matmul_int8_jit(a, b, u, v, bm=t.bm, bn=t.bn, bk=t.bk,
                                 interpret=resolve_interpret(interpret))
 
 
-def _lut_gather_accum(a, b, flat, acc):
-    """acc + sum_k LUT[a_k, b_k] outer products — the shared gather sweep
-    of the full-table variants (flat, grouped, and fused-attention)."""
-    bm, bk = a.shape
-    bn = b.shape[1]
-    ia = a.astype(jnp.int32) + 128
-    ib = b.astype(jnp.int32) + 128
-
-    def body(k, acc):
-        # flat index LUT[a_k, b_k] = flat[a_k * 256 + b_k], outer-product shaped
-        iak = jax.lax.dynamic_index_in_dim(ia, k, axis=1, keepdims=True)   # (bm, 1)
-        ibk = jax.lax.dynamic_index_in_dim(ib, k, axis=0, keepdims=True)   # (1, bn)
-        idx = iak * 256 + ibk                                              # (bm, bn)
-        return acc + jnp.take(flat, idx.reshape(-1), axis=0).reshape(bm, bn)
-
-    return jax.lax.fori_loop(0, bk, body, acc)
+def check_lut_range(max_abs: int) -> None:
+    """The byte planes are bf16-exact only while every |entry| < 2**15."""
+    if max_abs >= _LUT_PLANE_LIMIT:
+        raise ValueError(
+            f"full-LUT kernel splits the table into two bf16 byte planes, "
+            f"exact only for |entry| < {_LUT_PLANE_LIMIT}; got {max_abs}")
 
 
-def _amr_matmul_lut_kernel(a_ref, b_ref, lut_ref, out_ref, acc_ref, *, n_k: int):
-    """Full-table variant: per-K-step (bm, bn) gather from the flat LUT."""
+def _lut_planes(table):
+    """(256, 256) int32 LUT[a, b] -> (512, 256) bf16 [high byte; low byte]
+    planes, table = 256 * high + low (see ``check_lut_range``)."""
+    table = table.astype(jnp.int32)
+    planes = jnp.concatenate([table >> 8, table & 255], axis=0)
+    return planes.astype(jnp.bfloat16)
+
+
+def _lut_block(ia_ref, ib_ref, lut_ref, acc_ref):
+    """acc += sum over the block's K rows of LUT[a_k, b_k] — bit-exact.
+
+    Per row k: ``planes @ one_hot(b_k)`` gathers the table columns of b_k
+    (512, bn), and ``one_hot(a_k)^T @ those`` picks each row's entry.  Each
+    dot has one nonzero term per output, and the f32 byte-plane sums stay
+    below 2**24 for any K < 2**16, so both are exact; they recombine in
+    int32."""
+    planes = lut_ref[...]                           # (512, 256) bf16
+
+    def body(k, carry):
+        hi, lo = carry
+        cols = jnp.dot(planes, _one_hot_row(ib_ref, k),
+                       preferred_element_type=jnp.float32).astype(jnp.bfloat16)
+        oh_a = _one_hot_row(ia_ref, k)
+        hi = hi + jax.lax.dot_general(oh_a, cols[:_VALUES], _LEADING,
+                                      preferred_element_type=jnp.float32)
+        lo = lo + jax.lax.dot_general(oh_a, cols[_VALUES:], _LEADING,
+                                      preferred_element_type=jnp.float32)
+        return hi, lo
+
+    zero = jnp.zeros(acc_ref.shape, jnp.float32)
+    hi, lo = jax.lax.fori_loop(0, ia_ref.shape[0], body, (zero, zero))
+    acc_ref[...] += hi.astype(jnp.int32) * 256 + lo.astype(jnp.int32)
+
+
+def _lut_scratch(bm: int, bn: int, bk: int):
+    return [pltpu.VMEM((bk, bm), jnp.int32), pltpu.VMEM((bk, bn), jnp.int32),
+            pltpu.VMEM((bm, bn), jnp.int32)]
+
+
+def _amr_matmul_lut_kernel(at_ref, b_ref, lut_ref, out_ref,
+                           ia_ref, ib_ref, acc_ref, *, n_k: int):
+    """Full-table variant: one (bm, bn) int32 block, K innermost."""
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    flat = lut_ref[...].reshape(-1)                 # (65536,) int32
-    acc_ref[...] = _lut_gather_accum(a_ref[...], b_ref[...], flat, acc_ref[...])
+    _load_indices(ia_ref, ib_ref, at_ref[...], b_ref[...])
+    _lut_block(ia_ref, ib_ref, lut_ref, acc_ref)
 
     @pl.when(k_idx == n_k - 1)
     def _store():
@@ -154,26 +275,29 @@ def _amr_matmul_lut_kernel(a_ref, b_ref, lut_ref, out_ref, acc_ref, *, n_k: int)
 def _amr_matmul_int8_lut_jit(a, b, table, *, bm, bn, bk, interpret):
     M, K = a.shape
     N = b.shape[1]
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    assert K % bk == 0, (K, bk)
+    at, bp = _pad_operands(a, b, bm, bn)
+    planes = _lut_planes(table)
     n_k = K // bk
-    grid = (M // bm, N // bn, n_k)
-    return pl.pallas_call(
+    grid = (at.shape[1] // bm, bp.shape[1] // bn, n_k)
+    out = pl.pallas_call(
         functools.partial(_amr_matmul_lut_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec(table.shape, lambda i, j, k: (0, 0)),  # whole LUT: 256KB VMEM
+            pl.BlockSpec(planes.shape, lambda i, j, k: (0, 0)),  # whole LUT
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((at.shape[1], bp.shape[1]), jnp.int32),
+        scratch_shapes=_lut_scratch(bm, bn, bk),
         interpret=interpret,
-    )(a, b, table)
+    )(at, bp, planes)
+    return out[:M, :N]
 
 
-def _amr_matmul_lut_grouped_kernel(a_ref, b_ref, lut_ref, out_ref, acc_ref,
-                                   *, n_k: int):
+def _amr_matmul_lut_grouped_kernel(at_ref, b_ref, lut_ref, out_ref,
+                                   ia_ref, ib_ref, acc_ref, *, n_k: int):
     """Grouped full-LUT variant: independent (M, K) @ (K, N) per group.
 
     Grid ``(G, M/bm, N/bn, K/bk)`` — one leading grid axis per group (the
@@ -186,8 +310,8 @@ def _amr_matmul_lut_grouped_kernel(a_ref, b_ref, lut_ref, out_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    flat = lut_ref[...].reshape(-1)                 # (65536,) int32
-    acc_ref[...] = _lut_gather_accum(a_ref[0], b_ref[0], flat, acc_ref[...])
+    _load_indices(ia_ref, ib_ref, at_ref[0], b_ref[0])
+    _lut_block(ia_ref, ib_ref, lut_ref, acc_ref)
 
     @pl.when(k_idx == n_k - 1)
     def _store():
@@ -198,29 +322,50 @@ def _amr_matmul_lut_grouped_kernel(a_ref, b_ref, lut_ref, out_ref, acc_ref,
 def _amr_matmul_int8_lut_grouped_jit(a, b, table, *, bm, bn, bk, interpret):
     G, M, K = a.shape
     N = b.shape[2]
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (G, M, N, K, bm, bn, bk)
+    assert K % bk == 0, (K, bk)
+    at, bp = _pad_operands(a, b, bm, bn)
+    planes = _lut_planes(table)
     n_k = K // bk
-    grid = (G, M // bm, N // bn, n_k)
-    return pl.pallas_call(
+    grid = (G, at.shape[2] // bm, bp.shape[2] // bn, n_k)
+    out = pl.pallas_call(
         functools.partial(_amr_matmul_lut_grouped_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda g, i, j, k: (g, i, k)),
+            pl.BlockSpec((1, bk, bm), lambda g, i, j, k: (g, k, i)),
             pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-            pl.BlockSpec(table.shape, lambda g, i, j, k: (0, 0)),
+            pl.BlockSpec(planes.shape, lambda g, i, j, k: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
-        out_shape=jax.ShapeDtypeStruct((G, M, N), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((G, at.shape[2], bp.shape[2]), jnp.int32),
+        scratch_shapes=_lut_scratch(bm, bn, bk),
         interpret=interpret,
-    )(a, b, table)
+    )(at, bp, planes)
+    return out[:, :M, :N]
 
 
 def amr_matmul_int8_lut(a: jnp.ndarray, b: jnp.ndarray, table: jnp.ndarray,
-                        *, bm: int = 128, bn: int = 128, bk: int = 128,
+                        *, bm: int | None = None, bn: int | None = None,
+                        bk: int | None = None,
                         interpret: bool | None = None) -> jnp.ndarray:
     """Bit-exact variant: a (M,K) int8, b (K,N) int8, table (256,256) int32
     -> (M,N) int32 — int32 accumulation of true AMR products (exact for
-    K * 2^16 < 2^31, i.e. any realistic K)."""
-    return _amr_matmul_int8_lut_jit(a, b, table, bm=bm, bn=bn, bk=bk,
+    K * 2^16 < 2^31, i.e. any realistic K).  Tiles as ``amr_matmul_int8``."""
+    check_lut_range(int(np.abs(np.asarray(table)).max()))
+    t = pick_tiles(a.shape[0], b.shape[1], a.shape[1], variant="lut",
+                   bm=bm, bn=bn, bk=bk)
+    return _amr_matmul_int8_lut_jit(a, b, table, bm=t.bm, bn=t.bn, bk=t.bk,
                                     interpret=resolve_interpret(interpret))
+
+
+def amr_matmul_int8_lut_grouped(a: jnp.ndarray, b: jnp.ndarray,
+                                table: jnp.ndarray, *, bm: int | None = None,
+                                bn: int | None = None, bk: int | None = None,
+                                interpret: bool | None = None) -> jnp.ndarray:
+    """Grouped bit-exact variant: a (G,M,K) int8, b (G,K,N) int8 -> (G,M,N)
+    int32, one independent LUT matmul per group."""
+    check_lut_range(int(np.abs(np.asarray(table)).max()))
+    t = pick_tiles(a.shape[1], b.shape[2], a.shape[2], variant="lut_grouped",
+                   bm=bm, bn=bn, bk=bk)
+    return _amr_matmul_int8_lut_grouped_jit(
+        a, b, table, bm=t.bm, bn=t.bn, bk=t.bk,
+        interpret=resolve_interpret(interpret))

@@ -2,10 +2,10 @@
 
 Dispatches between the two kernel variants (kernel.py):
 
-  * ``method="lowrank"`` — rank-r SVD factors of the error table, single
-    augmented MXU dot per block; per-product error <= sigma_{r+1} of the
-    error table's spectrum (core/lut.py documents the bound);
-  * ``method="lut"``     — full 256x256 int32 table gather, bit-exact AMR
+  * ``method="lowrank"`` — rank-r SVD factors of the error table, one
+    augmented MXU contraction per block; per-product error <= sigma_{r+1}
+    of the error table's spectrum (core/lut.py documents the bound);
+  * ``method="lut"``     — full 256x256 int32 table lookup, bit-exact AMR
     products with int32 accumulation.
 
 Both source their constants from ``core/lut.py``'s cached accessors — the
@@ -14,10 +14,10 @@ process by the fused multi-border engine and converted to jnp once
 (``lut.factor_arrays`` / ``lut.table_array``); no call site rebuilds them.
 
 Tiling (``bm/bn/bk=None``) and execution mode (``interpret=None``) resolve
-in THIS non-jitted wrapper — tiles from the shared backend-keyed autotune
-table clamped to shape divisors, interpret from the backend autodetect
-with the ``REPRO_PALLAS_INTERPRET`` env override — then the jitted inner
-function is keyed on the concrete values.
+in THIS non-jitted wrapper — aligned tiles from the shared backend-keyed
+autotune table (the jitted kernels pad M and N up to them), interpret from
+the backend autodetect with the ``REPRO_PALLAS_INTERPRET`` env override —
+then the jitted inner function is keyed on the concrete values.
 """
 from __future__ import annotations
 
@@ -31,7 +31,7 @@ from repro.kernels.pallas_config import resolve_interpret
 from repro.numerics.quant import quantize_int8
 
 from .kernel import (_amr_matmul_int8_jit, _amr_matmul_int8_lut_grouped_jit,
-                     _amr_matmul_int8_lut_jit)
+                     _amr_matmul_int8_lut_jit, check_lut_range)
 from .tiling import pick_tiles
 
 
@@ -73,6 +73,8 @@ def amr_matmul(a: jnp.ndarray, b: jnp.ndarray, *, border: int | None = 8,
     (quantize -> kernel variant -> rescale)."""
     if method not in ("lowrank", "lut"):
         raise ValueError(f"method must be 'lowrank' or 'lut', got {method!r}")
+    if method == "lut":
+        check_lut_range(lut_lib.table_max_abs(border))
     tiles = pick_tiles(a.shape[0], b.shape[1], a.shape[1],
                        variant=method, bm=bm, bn=bn, bk=bk)
     return _amr_matmul_jit(a, b, border=border, rank=rank, method=method,
@@ -103,12 +105,13 @@ def amr_matmul_grouped(a: jnp.ndarray, b: jnp.ndarray, *,
     Quantization follows the seam convention (per-row of A, per-column of
     B), so the output is bit-identical to stacking per-group
     ``amr_matmul(..., method="lut")`` calls.  Tiles come from the shared
-    autotune table (variant ``lut_grouped``) clamped to shape divisors.
+    autotune table (variant ``lut_grouped``); the kernel pads M and N.
     """
     if a.ndim != 3 or b.ndim != 3 or a.shape[0] != b.shape[0]:
         raise ValueError(
             f"amr_matmul_grouped takes (G, M, K) @ (G, K, N) with matching "
             f"group counts, got {a.shape} @ {b.shape}")
+    check_lut_range(lut_lib.table_max_abs(border))
     tiles = pick_tiles(a.shape[1], b.shape[2], a.shape[2],
                        variant="lut_grouped", bm=bm, bn=bn, bk=bk)
     return _amr_matmul_grouped_jit(a, b, border=border, bm=tiles.bm,

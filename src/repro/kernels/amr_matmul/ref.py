@@ -1,8 +1,8 @@
 """Pure-jnp/numpy oracles for the AMR matmul kernel variants.
 
 ``ref_lowrank_int8`` mirrors the low-rank kernel's math densely
-(A@B + U[A]@V[B] einsum contraction) — agreement with the kernel is to
-f32 accumulation order.  ``ref_bitexact_int8`` is the ground truth for
+(A@B + sum_j U_j[A] @ V_j[B]) — agreement with the kernel is to f32
+accumulation order.  ``ref_bitexact_int8`` is the ground truth for
 BOTH the full-LUT kernel (which must match it bit-for-bit, int64 exact)
 and the rank-256 low-rank kernel (which matches to fp32 rounding): it
 accumulates per-element products straight from the engine-built 256x256
@@ -18,12 +18,16 @@ from repro.core import lut as lut_lib
 
 
 def ref_lowrank_int8(a: jnp.ndarray, b: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray):
-    """Same math as the kernel, dense jnp: A@B + U[A]@V[B] contraction."""
-    fa = a.astype(jnp.float32)
-    fb = b.astype(jnp.float32)
-    ua = u[a.astype(jnp.int32) + 128]          # (M, K, r)
-    vb = v[b.astype(jnp.int32) + 128]          # (K, N, r)
-    return fa @ fb + jnp.einsum("mkr,knr->mn", ua, vb)
+    """Same math as the kernel, dense jnp: A@B + sum_j U_j[A] @ V_j[B].
+
+    One (M, K) @ (K, N) matmul per factor lane: a (K, N, r) gather would
+    put r in the minor dim, which TPU layouts pad to 128 lanes."""
+    ia = a.astype(jnp.int32) + 128
+    ib = b.astype(jnp.int32) + 128
+    out = a.astype(jnp.float32) @ b.astype(jnp.float32)
+    for j in range(u.shape[1]):
+        out = out + u[:, j][ia] @ v[:, j][ib]
+    return out
 
 
 def ref_bitexact_int8(a: np.ndarray, b: np.ndarray, border: int) -> np.ndarray:

@@ -1,27 +1,37 @@
 """Shared tiling policy for the amr_matmul kernel variants.
 
-One autotune table keyed on ``(backend, variant)`` serves both the
-low-rank MXU kernel and the full-table LUT-gather kernel; callers pass
-``bm/bn/bk=None`` to take the table entry, clamped down to divisors of the
-actual problem shape so ``pallas_call`` grids always tile exactly.
+One autotune table keyed on ``(backend, variant)`` serves the low-rank
+and full-table kernels and the injection replay; callers pass
+``bm/bn/bk=None`` to take the table entry.
+
+Auto tiles PAD, they never clamp to a divisor: Mosaic accepts a block only
+when its last two dims are multiples of the (8, 128) layout tile (32 rows
+for int8 operands) or equal the whole array dim, and a divisor of an
+awkward shape (decode's handful of rows, vocab 50280) is rarely aligned.
+So ``bm``/``bn`` come out aligned, a problem smaller than the preferred
+tile gets one block of its own size rounded up to the alignment, and the
+op wrappers pad M and N up to whole blocks and slice the result.  K is
+never padded (the AMR product of a zero pad need not be 0): ``bk`` is the
+largest aligned divisor of K up to the preference, else all of K.
 
 Entries encode where each variant is bound:
 
-  * ``lowrank`` is MXU-bound — big square 128-multiple tiles keep the
-    (bm, bk*(1+r)) x (bk*(1+r), bn) dot on the systolic array;
-  * ``lut`` is VPU/gather-bound and walks K sequentially inside the block,
-    so K tiles shrink on real accelerators to bound the per-step gather
-    footprint while M/N stay MXU-tile aligned for the output block;
+  * ``lowrank`` and ``lut`` look up table rows with one-hot MXU dots per
+    K row, and the B-side lookups are redone for every row block, so both
+    prefer tall (256-row) blocks; ``lowrank`` holds (bk*R, bm) and
+    (bk*R, bn) f32 lookups (R = rank + 1, padded to 8) and so takes
+    bk=64 to stay inside the 16 MiB of scoped VMEM Mosaic grants a kernel
+    on v5e;
   * ``inject_replay`` (kernels/inject_replay) holds the whole bit-sliced
     wire state of a block in VMEM — ~n_wires uint32 words per (m, k) pair
     per 32 output columns — so its M/K tiles are much smaller than the
-    LUT variants'; its n dimension is blocked in 32-column lane words, so
-    preferred ``bn`` entries are multiples of 32 (the op wrapper clamps
-    autotuned tiles to word-aligned divisors).
+    LUT variants'; its n dimension is blocked in 32-column lane words.
+    Mosaic refuses that kernel (pallas_config.REFUSED_ON_TPU), so its rows
+    only shape the interpreter's grid.
 
 Explicit ``bm/bn/bk`` overrides win over the table but must divide the
-problem shape exactly — a non-divisor would leave a partial tile the
-grids of these kernels never visit, so ``pick_tiles`` rejects it.
+problem shape exactly, and are taken as given (no alignment): they serve
+interpreter tests and benches that pin a grid.
 """
 from __future__ import annotations
 
@@ -37,14 +47,14 @@ class TileConfig:
     bk: int
 
 
-# (backend, variant) -> preferred tiles; clamped to shape divisors at pick
-# time. The gpu rows size VMEM-equivalent footprints for a future Triton
+# (backend, variant) -> preferred tiles, aligned and padded at pick time.
+# The gpu rows size VMEM-equivalent footprints for a future Triton
 # variant — today GPU runs the interpreter (pallas_config) so they only
 # shape the grid.
 AUTOTUNE: dict[tuple[str, str], TileConfig] = {
-    ("tpu", "lowrank"): TileConfig(128, 128, 128),
-    ("tpu", "lut"): TileConfig(128, 128, 32),
-    ("tpu", "lut_grouped"): TileConfig(128, 128, 32),
+    ("tpu", "lowrank"): TileConfig(256, 256, 64),
+    ("tpu", "lut"): TileConfig(256, 256, 128),
+    ("tpu", "lut_grouped"): TileConfig(256, 256, 128),
     ("tpu", "inject_replay"): TileConfig(32, 128, 8),
     ("gpu", "lowrank"): TileConfig(64, 128, 64),
     ("gpu", "lut"): TileConfig(64, 128, 32),
@@ -57,6 +67,12 @@ AUTOTUNE: dict[tuple[str, str], TileConfig] = {
 }
 
 VARIANTS = ("lowrank", "lut", "lut_grouped", "inject_replay")
+
+# Mosaic layout tile (sublane, lane) of an f32/int32 block, and the K
+# alignment each variant's operand blocks need: int8 blocks tile 32 rows;
+# the injection replay's int32 operands are interpreter-only.
+SUBLANE, LANE = 8, 128
+K_ALIGN = {"lowrank": 32, "lut": 32, "lut_grouped": 32, "inject_replay": 1}
 
 # Fused-attention query-row tiles (kernels/attn_fused), keyed on the
 # backend and a HEAD-DIM BUCKET: the kernel holds a whole (bm, T) score
@@ -92,16 +108,33 @@ def _largest_divisor_leq(n: int, cap: int) -> int:
     return 1
 
 
-def _resolve_dim(name: str, dim_name: str, size: int, override: int | None,
-                 pref: int) -> int:
-    if override is None:
-        return _largest_divisor_leq(size, pref)
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _check_override(name: str, dim_name: str, size: int, override: int) -> int:
     if override < 1 or size % override:
         raise ValueError(
             f"{name}={override} does not tile the problem: {dim_name}={size} "
             f"is not a multiple (the grid would miss a partial tile); pass "
-            f"None to take the autotune entry clamped to a divisor")
+            f"None to take the aligned autotune entry (the op pads M and N)")
     return override
+
+
+def _resolve_dim(name: str, dim_name: str, size: int, override: int | None,
+                 pref: int) -> int:
+    if override is None:
+        return _largest_divisor_leq(size, pref)
+    return _check_override(name, dim_name, size, override)
+
+
+def _aligned_k(k: int, pref: int, align: int) -> int:
+    """Largest divisor of k that is <= pref and a multiple of align, else k
+    (a block spanning the whole dim is always accepted)."""
+    for d in range(min(k, pref) // align * align, 0, -align):
+        if k % d == 0:
+            return d
+    return k
 
 
 def pick_tiles(
@@ -110,13 +143,16 @@ def pick_tiles(
 ) -> TileConfig:
     """Resolve block sizes: explicit overrides win (validated to divide the
     problem shape exactly), else the autotune entry for the (detected)
-    backend, clamped to the largest divisor of its dimension so the grid
-    covers the problem exactly."""
+    backend, aligned as the module docstring says — the op pads M up to a
+    multiple of ``bm`` and N up to a multiple of ``bn``; ``bk`` divides k."""
     if variant not in VARIANTS:
         raise ValueError(f"variant must be one of {VARIANTS}, got {variant!r}")
     pref = AUTOTUNE[(backend or backend_kind(), variant)]
     return TileConfig(
-        bm=_resolve_dim("bm", "m", m, bm, pref.bm),
-        bn=_resolve_dim("bn", "n", n, bn, pref.bn),
-        bk=_resolve_dim("bk", "k", k, bk, pref.bk),
+        bm=(_check_override("bm", "m", m, bm) if bm is not None
+            else min(pref.bm, _round_up(m, SUBLANE))),
+        bn=(_check_override("bn", "n", n, bn) if bn is not None
+            else min(pref.bn, _round_up(n, LANE))),
+        bk=(_check_override("bk", "k", k, bk) if bk is not None
+            else _aligned_k(k, pref.bk, K_ALIGN[variant])),
     )

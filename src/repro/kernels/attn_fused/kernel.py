@@ -11,8 +11,7 @@ round-trips to HBM between QK^T and PV.
 Two methods, mirroring the seam's integer paths:
 
   * ``lut``    — both contractions gather from the full 256x256 product
-    table (``amr_matmul._lut_gather_accum``, the same sweep the flat and
-    grouped LUT kernels use); bit-identical to the ``amr_lut`` seam
+    table (``_lut_gather_accum``); bit-identical to the ``amr_lut`` seam
     composition by construction.
   * ``inject`` — both contractions replay the reduction circuit on
     lane-packed operand words (``inject_replay._replay_block`` — the exact
@@ -52,10 +51,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.engine import _LANE_BITS
-from repro.kernels.amr_matmul.kernel import _lut_gather_accum
 from repro.kernels.inject_replay.kernel import _replay_block, _replay_inputs
 
 NEG_INF = -2.0e38  # the models/attention.py mask fill, bit for bit
+
+
+def _lut_gather_accum(a, b, flat, acc):
+    """acc + sum_k LUT[a_k, b_k] outer products, gathered from the flat
+    table (interpreter only: Mosaic lowers neither the gather nor the
+    dynamic lane slice)."""
+    bm, bk = a.shape
+    bn = b.shape[1]
+    ia = a.astype(jnp.int32) + 128
+    ib = b.astype(jnp.int32) + 128
+
+    def body(k, acc):
+        # flat index LUT[a_k, b_k] = flat[a_k * 256 + b_k], outer-product shaped
+        iak = jax.lax.dynamic_index_in_dim(ia, k, axis=1, keepdims=True)   # (bm, 1)
+        ibk = jax.lax.dynamic_index_in_dim(ib, k, axis=0, keepdims=True)   # (1, bn)
+        idx = iak * 256 + ibk                                              # (bm, bn)
+        return acc + jnp.take(flat, idx.reshape(-1), axis=0).reshape(bm, bn)
+
+    return jax.lax.fori_loop(0, bk, body, acc)
 
 
 def _quantize_probs(probs):

@@ -7,21 +7,23 @@ kernel whose tiles come from the shared autotune table
 (``amr_matmul/tiling.py``, variant ``inject_replay``).  Dispatch between
 the two lives in ``numerics.approx_matmul.matmul_amr_inject`` via the
 ``AMRNumerics.inject_impl`` policy field, resolved by
-``kernels/pallas_config.resolve_inject_impl`` (compiled Pallas on real
-TPU, XLA elsewhere, ``REPRO_INJECT_IMPL`` overrides).
+``kernels/pallas_config.resolve_inject_impl`` (XLA unless asked for).
+The TPU compiler refuses this kernel (``pallas_config.REFUSED_ON_TPU``):
+requested compiled, it raises ``KernelRefusedError``.
 
 The n dimension is blocked in WORD units: 32 output columns share one
 uint32 lane word, so an explicit ``bn`` override must be a multiple of 32
-(as well as dividing the padded column count) — the autotune path clamps
-to word-aligned divisors automatically.
+(as well as dividing the padded column count).  Autotuned tiles pad: rows
+up to a multiple of ``bm`` and words up to a multiple of the word tile.
 """
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import _LANE_BITS, CompiledInjector
-from repro.kernels.amr_matmul.tiling import _largest_divisor_leq, pick_tiles
-from repro.kernels.pallas_config import resolve_interpret
+from repro.kernels.amr_matmul.tiling import pick_tiles
+from repro.kernels.pallas_config import check_compilable, resolve_interpret
 
 from .kernel import _inject_replay_jit
 
@@ -39,11 +41,14 @@ def inject_replay_matmul(inj: CompiledInjector, ia, ib, *,
     Weight packing goes through the shared ``packed_weights`` cache (packed
     once per matmul in-trace; cached across calls for concrete weights) —
     or is bypassed entirely by a precomputed ``packed_ib``.  Raises at
-    trace time when K could saturate the int32 accumulator.
+    trace time when K could saturate the int32 accumulator, and
+    ``KernelRefusedError`` when asked to run compiled (as on TPU).
     """
     from repro.numerics.injection import (check_accumulation_bound,
                                           packed_weights)
 
+    interpret = resolve_interpret(interpret)
+    check_compilable("inject_replay", interpret)
     *lead, m, k = ia.shape
     n = ib.shape[-1]
     check_accumulation_bound(inj, k, schedule=schedule)
@@ -62,11 +67,13 @@ def inject_replay_matmul(inj: CompiledInjector, ia, ib, *,
     # padded column count — pick_tiles errors report those quantities
     tiles = pick_tiles(rows, npad, k, variant="inject_replay",
                        bm=bm, bn=bn, bk=bk)
-    if bn is not None:
-        bnw = bn // _LANE_BITS
-    else:  # word-align the autotuned tile: largest word-count divisor
-        bnw = _largest_divisor_leq(n_words, max(1, tiles.bn // _LANE_BITS))
-    out = _inject_replay_jit(ia.reshape(rows, k), yw, inj._value_masks,
+    bnw = max(1, tiles.bn // _LANE_BITS)
+    # pad rows with index 128 (operand value 0) and words with zero words;
+    # both land in output rows / columns that are sliced off below
+    ia2 = jnp.pad(ia.reshape(rows, k), [(0, -rows % tiles.bm), (0, 0)],
+                  constant_values=128)
+    yw = jnp.pad(yw, [(0, 0), (0, 0), (0, -n_words % bnw)])
+    out = _inject_replay_jit(ia2, yw, inj._value_masks,
                              lowered=inj.lowered, bm=tiles.bm, bnw=bnw,
-                             bk=tiles.bk, interpret=resolve_interpret(interpret))
-    return out[:, :n].reshape(*lead, m, n)
+                             bk=tiles.bk, interpret=interpret)
+    return out[:rows, :n].reshape(*lead, m, n)

@@ -13,10 +13,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # legacy jax: make_mesh has no axis_types kwarg
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,14 +27,6 @@ def make_host_mesh(model_parallel: int = 1):
     """Mesh over whatever devices exist (tests / CPU examples)."""
     n = len(jax.devices())
     return _make_mesh((n // model_parallel, model_parallel), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where it exists; on legacy jax the ``Mesh``
-    itself is the context manager that activates it (single-device launcher
-    runs — the production dry-run always uses modern jax)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
 
 
 # Hardware constants for the roofline (TPU v5e, per chip)

@@ -28,7 +28,7 @@ import numpy as np
 
 from repro.configs.registry import get_config, get_reduced_config
 from repro.launch.cli import add_numerics_args, apply_pallas_interpret, numerics_from_args
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
 from repro.numerics import root_key
 from repro.runtime import Heartbeat
@@ -38,8 +38,8 @@ from repro.serve import Request, ServeEngine
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--reduced", action="store_true",
+                    help="use the smoke-scale config (CPU-friendly)")
     ap.add_argument("--requests", type=int, default=8,
                     help="number of generation requests to serve")
     ap.add_argument("--slots", type=int, default=4,
@@ -71,7 +71,7 @@ def main(argv=None) -> None:
                for _ in range(args.requests)]
     hb = Heartbeat(Path(args.heartbeat)) if args.heartbeat else None
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, root_key(args.seed))
         engine = ServeEngine(cfg, params, n_slots=args.slots, capacity=capacity,
                              heartbeat=hb, log=print)

@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.registry import get_config, get_reduced_config
 from repro.data import SyntheticLM
 from repro.launch.cli import add_numerics_args, apply_pallas_interpret, numerics_from_args
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.numerics import root_key
 from repro.parallel import sharding as shard_lib
 from repro.runtime import FaultTolerantLoop, Heartbeat
@@ -63,7 +63,7 @@ def main(argv=None) -> None:
                                microbatch=args.microbatch or None)
 
     def make_state():
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             state = make_train_state(cfg, root_key(args.seed))
             specs = shard_lib.param_specs(mesh, state, cfg)
             sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
@@ -82,7 +82,7 @@ def main(argv=None) -> None:
 
     def step_fn(state, batch):
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             return jitted(state, batch)
 
     hb = Heartbeat(Path(args.ckpt_dir) / "heartbeat.json")

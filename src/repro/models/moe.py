@@ -198,7 +198,6 @@ def _moe_local_body(xf, router, w_gate, w_up, w_down, cfg: MoEConfig,
 def _moe_forward_local(params, x, cfg: MoEConfig, *, capacity_factor, numerics):
     """shard_map dispatch: tokens never leave their data shard."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel.constraints import _ambient_axes
@@ -225,11 +224,11 @@ def _moe_forward_local(params, x, cfg: MoEConfig, *, capacity_factor, numerics):
     body = lambda xs, r, wg, wu, wd: _moe_local_body(
         xs, r, wg, wu, wd, cfg, capacity_factor, batch_axes,
         model_axis if tp_ok else None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=jax.sharding.get_abstract_mesh(),
         in_specs=(x_spec, P(None, None), w_col, w_col, w_row),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(xf, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     return out.reshape(B, S, D), aux

@@ -24,9 +24,10 @@ Modes (DESIGN.md §2/§3; docs/numerics.md has the full dispatch table):
                  numerics.context (site labels + the ambient scope).
   amr_kernel   — the production Pallas kernel path (kernels/amr_matmul):
                  low-rank MXU kernel at numerics.rank, or the bit-exact
-                 full-table LUT-gather kernel when rank == 0. Compiled on
-                 real TPU backends, interpreter mode on CPU/GPU
-                 (REPRO_PALLAS_INTERPRET overrides; kernels/pallas_config).
+                 full-table LUT kernel when rank == 0. Compiled on TPU
+                 (both compile for v5e: tests/test_tpu_compile.py),
+                 interpreter mode on CPU/GPU (REPRO_PALLAS_INTERPRET
+                 overrides; kernels/pallas_config).
 
 All functions take A: (..., M, K) and B: (K, N) **or** a batched
 B: (..., K, N) whose leading dims broadcast against A's — the weight-matmul
@@ -91,8 +92,9 @@ class AMRNumerics:
     # — the policy itself must stay hashable for jit).
     schedule_ref: str | None = None
     # amr_inject implementation: "xla" (outer-product replay in the trace),
-    # "pallas" (kernels/inject_replay), or None = backend autodetect with
-    # the REPRO_INJECT_IMPL env override (kernels/pallas_config).
+    # "pallas" (kernels/inject_replay, refused by the TPU compiler), or
+    # None = "xla" unless REPRO_INJECT_IMPL says otherwise
+    # (kernels/pallas_config).
     inject_impl: str | None = None
 
     def __post_init__(self):
@@ -161,10 +163,13 @@ def matmul_amr_lut(a: jnp.ndarray, b: jnp.ndarray, border: int) -> jnp.ndarray:
 def matmul_amr_lowrank(a: jnp.ndarray, b: jnp.ndarray, border: int, rank: int) -> jnp.ndarray:
     """MXU formulation of AMR-MUL semantics (§Perf cell P, iteration 3).
 
-    Forward: augmented-K single dot (same lane layout as kernels/amr_matmul)
-    — per k the contraction lanes are [exact, err_1..err_r] on BOTH sides,
-    ONE matmul over K*(1+r) with bf16 error lanes (int8-grid exact lanes are
-    bf16-exact). No f32 (K,N,r) correction tensor materialises/reshards.
+    Forward: one augmented contraction over (lane, k) with lanes
+    [exact, err_1..err_r] on BOTH sides — the same sum the low-rank kernel
+    (kernels/amr_matmul) runs per block — with bf16 error lanes (int8-grid
+    exact lanes are bf16-exact) and f32 accumulation.  The lane axis leads
+    each operand, (1+r, ..., M, K) and (1+r, ..., K, N): as a minor dim of
+    width 1+r it would be padded to 128 lanes on TPU, 8x the bytes at
+    r=16.  No f32 (K,N,r) correction tensor materialises/reshards.
 
     Backward (custom_vjp): plain full-precision matmul vjp — the explicit
     straight-through surrogate. Guarantees the (1+r)x flops are paid ONLY on
@@ -179,16 +184,26 @@ def _lowrank_fwd(a, b, border, rank):
     qb, sb = quantize_int8_ste(b, axis=-2)
     ia = jax.lax.stop_gradient(qa).astype(jnp.int32) + 128
     ib = jax.lax.stop_gradient(qb).astype(jnp.int32) + 128
-    K = a.shape[-1]
-    ua = u[ia].astype(jnp.bfloat16)              # (..., M, K, r) 1-D LUTs
-    vb = v[ib].astype(jnp.bfloat16)              # (..., K, N, r)
-    a_aug = jnp.concatenate([qa[..., None].astype(jnp.bfloat16), ua], axis=-1)
-    a_aug = a_aug.reshape(*a.shape[:-1], K * (1 + rank))
-    b_aug = jnp.concatenate([qb[..., :, None, :].astype(jnp.bfloat16),
-                             jnp.moveaxis(vb, -1, -2)], axis=-2)
-    b_aug = b_aug.reshape(*b.shape[:-2], K * (1 + rank), b.shape[-1])
-    out = jnp.matmul(a_aug, b_aug, preferred_element_type=jnp.float32)
+    a_aug = jnp.concatenate([qa[None].astype(jnp.bfloat16),
+                             _lane_lookup(u, ia)])   # (1+r, ..., M, K)
+    b_aug = jnp.concatenate([qb[None].astype(jnp.bfloat16),
+                             _lane_lookup(v, ib)])   # (1+r, ..., K, N)
+    spec = "j...mk,jkn->...mn" if b.ndim == 2 else "j...mk,j...kn->...mn"
+    out = jnp.einsum(spec, a_aug, b_aug, preferred_element_type=jnp.float32)
     return out * sa * sb, (a, b)
+
+
+def _lane_lookup(table, idx):
+    """``table[idx]`` with the lane axis leading: (256, r), (...) -> (r, ...)
+    bf16, as ``table^T @ one_hot(idx)`` on the MXU.  Exact (one nonzero
+    term per output); TPU gathers of this size run far slower, and a
+    (256, r) gather emits its r-wide slice as the minor dim."""
+    flat = idx.reshape(1, -1)
+    one_hot = (jax.lax.broadcasted_iota(jnp.int32, (table.shape[0], flat.shape[1]), 0)
+               == flat).astype(jnp.bfloat16)
+    out = jnp.matmul(table.T.astype(jnp.bfloat16), one_hot,
+                     preferred_element_type=jnp.float32)
+    return out.astype(jnp.bfloat16).reshape(table.shape[1], *idx.shape)
 
 
 def _reduce_to_shape(g: jnp.ndarray, shape: tuple) -> jnp.ndarray:
@@ -278,7 +293,7 @@ def matmul_amr_inject(a: jnp.ndarray, b: jnp.ndarray, numerics: "AMRNumerics") -
     replay runs either as XLA ops in the surrounding trace
     (``injection.injected_matmul_int``, row+K-chunked) or as the Pallas
     injection-replay kernel (``kernels/inject_replay``), selected by
-    ``numerics.inject_impl`` (None = backend autodetect, docs/kernels.md);
+    ``numerics.inject_impl`` (None = xla, docs/kernels.md);
     both share the weight-side bit-pack and are bit-identical.
 
     Backward: the straight-through full-precision surrogate shared with
@@ -536,7 +551,7 @@ def _validate_inject(nm) -> None:
         if nm.inject_impl not in INJECT_IMPLS:
             raise ValueError(
                 f"inject_impl must be one of {INJECT_IMPLS} (or None = "
-                f"backend autodetect), got {nm.inject_impl!r}")
+                f"the default), got {nm.inject_impl!r}")
     if nm.schedule_ref is not None and not isinstance(nm.schedule_ref, str):
         raise ValueError(
             f"schedule_ref must be a registered-schedule handle (str) or "

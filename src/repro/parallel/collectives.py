@@ -46,13 +46,11 @@ def compressed_psum_tree(grads: Any, axis: str, stochastic: bool = False,
 
 def make_compressed_dp_allreduce(mesh, axis: str = "pod"):
     """shard_map-wrapped tree all-reduce over one mesh axis (e.g. cross-pod)."""
-    from jax.experimental.shard_map import shard_map
-
     def reduce_tree(grads):
         spec = jax.tree.map(lambda _: P(), grads)
-        f = shard_map(
+        f = jax.shard_map(
             lambda g: compressed_psum_tree(g, axis),
-            mesh=mesh, in_specs=(spec,), out_specs=spec, check_rep=False)
+            mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
         return f(grads)
 
     return reduce_tree

@@ -20,13 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 
 def _ambient_axes() -> dict[str, int]:
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:
-        # Legacy jax (pre-AxisType): no ambient-mesh API at all. Single-device
-        # model code must still run (smoke tests, examples), so pins degrade
-        # to no-ops exactly as they do with no mesh set.
-        return {}
-    m = get_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or not m.axis_names:
         return {}
     return dict(zip(m.axis_names, m.axis_sizes))

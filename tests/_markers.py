@@ -1,19 +1,7 @@
 """Shared environment-gating markers for the test suite."""
 import os
 
-import jax
 import pytest
-
-# Mesh/sharding machinery targets modern jax (jax.sharding.AxisType et al.);
-# on older jax it fails inside jax itself before testing anything of ours.
-#
-# Apply this ONLY to tests that actually build meshes / shardings /
-# shard_maps (or subprocesses that do).  Plain single-device forward /
-# train / decode paths run fine on legacy jax — ``parallel.constraints.pin``
-# degrades to a no-op there — and must NOT hide behind this guard.
-requires_modern_jax = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="requires modern jax.sharding (AxisType-era) APIs")
 
 # Full conformance-matrix sweeps (every arch x every mode) are minutes of
 # CPU — they run in the nightly workflow (REPRO_NIGHTLY=1), while tier-1

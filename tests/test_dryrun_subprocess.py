@@ -7,12 +7,8 @@ from pathlib import Path
 
 import pytest
 
-from _markers import requires_modern_jax
 
 REPO = Path(__file__).resolve().parents[1]
-
-# The dryrun subprocess needs the same modern-jax mesh APIs.
-pytestmark = requires_modern_jax
 
 
 @pytest.mark.slow

@@ -201,7 +201,8 @@ class TestWeightPackCache:
 class TestInjectImplPolicy:
     def test_autodetect_per_backend(self, monkeypatch):
         monkeypatch.delenv(pallas_config.INJECT_IMPL_ENV, raising=False)
-        for backend, impl in (("tpu", "pallas"), ("gpu", "xla"), ("cpu", "xla")):
+        # a static choice: the TPU compiler refuses the Pallas replay
+        for backend, impl in (("tpu", "xla"), ("gpu", "xla"), ("cpu", "xla")):
             monkeypatch.setattr(pallas_config, "backend_kind", lambda b=backend: b)
             assert pallas_config.default_inject_impl() == impl, backend
             assert pallas_config.resolve_inject_impl(None) == impl, backend
@@ -220,6 +221,23 @@ class TestInjectImplPolicy:
         assert pallas_config.resolve_inject_impl("xla") == "xla"
         with pytest.raises(ValueError, match="inject_impl"):
             pallas_config.resolve_inject_impl("mosaic")
+
+    def test_compiled_request_raises_named_refusal(self):
+        """Mosaic refuses the replay kernel: asked for compiled (what a TPU
+        resolves to) it names itself and the refusal, never falls back."""
+        inj = engine.get_injector(2, 8)
+        ia = jnp.full((4, 16), 128, jnp.int32)
+        with pytest.raises(pallas_config.KernelRefusedError,
+                           match="'inject_replay' does not compile for TPU"):
+            inject_replay_matmul(inj, ia, ia.T, interpret=False)
+
+    def test_pallas_policy_on_compiled_backend_raises(self, monkeypatch):
+        monkeypatch.setenv(pallas_config.ENV_VAR, "0")  # compiled, as on TPU
+        nm = AMRNumerics("amr_inject", border=8, inject_impl="pallas")
+        a = jnp.ones((4, 16), jnp.float32)
+        with pytest.raises(pallas_config.KernelRefusedError,
+                           match="Shape mismatch"):
+            jax.jit(lambda a, b: approx_matmul(a, b, nm))(a, a.T)
 
     def test_policy_field_stays_hashable(self):
         nm = AMRNumerics("amr_inject", border=8, inject_impl="pallas")

@@ -71,6 +71,25 @@ class TestAMRMatmulKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1.0)
 
 
+@pytest.mark.parametrize("m,n,k", [(4, 200, 96), (300, 130, 64), (8, 12, 7)])
+def test_auto_tiles_pad_awkward_shapes(m, n, k):
+    """Auto tiles pad M and N up to aligned blocks (decode's handful of
+    rows, a vocab that no 128-multiple divides) and slice the pad off."""
+    rng = np.random.default_rng(m * n + k)
+    a = jnp.asarray(rng.integers(-128, 128, (m, k)), jnp.int8)
+    b = jnp.asarray(rng.integers(-128, 128, (k, n)), jnp.int8)
+    u, v = lut_factors(border=8, rank=4)
+    got = amr_matmul_int8(a, b, u, v, interpret=True)
+    assert got.shape == (m, n)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref_lowrank_int8(a, b, u, v)),
+                               rtol=2e-4, atol=2.0)
+    got = np.asarray(amr_matmul_int8_lut(a, b, lut_lib.table_array(8),
+                                         interpret=True))
+    want = ref_bitexact_int8(np.asarray(a), np.asarray(b), border=8)
+    np.testing.assert_array_equal(got.astype(np.int64), want)
+
+
 class TestAMRMatmulLUTKernel:
     """Full-table LUT-gather variant: bit-exact AMR products."""
 
@@ -164,16 +183,25 @@ class TestPallasPolicy:
         assert pallas_config.resolve_interpret(True) is True
 
     def test_pick_tiles_divides_shapes(self):
-        for variant in ("lowrank", "lut", "inject_replay"):
-            for (m, n, k) in [(128, 128, 128), (96, 64, 160), (100, 12, 7)]:
-                t = pick_tiles(m, n, k, variant=variant)
-                assert m % t.bm == 0 and n % t.bn == 0 and k % t.bk == 0
+        """Auto tiles are (8, 128)-aligned and the op pads M and N up to
+        them; K is never padded, so bk divides it."""
+        for backend in ("cpu", "tpu"):
+            for variant in ("lowrank", "lut", "inject_replay"):
+                for (m, n, k) in [(128, 128, 128), (96, 64, 160), (100, 12, 7),
+                                  (4, 32000, 768)]:
+                    t = pick_tiles(m, n, k, variant=variant, backend=backend)
+                    assert t.bm % 8 == 0 and t.bn % 128 == 0, (t, m, n)
+                    assert k % t.bk == 0, (t, k)
+                    assert t.bm <= max(m + 7, 8) and t.bn <= max(n + 127, 128)
 
     def test_pick_tiles_overrides_and_backends(self):
         t = pick_tiles(256, 256, 256, variant="lut", backend="tpu")
-        assert t == TileConfig(128, 128, 32)  # autotune entry, no clamping
+        assert t == TileConfig(256, 256, 128)  # autotune entry, no clamping
         t = pick_tiles(256, 256, 256, variant="lut", backend="tpu", bk=256)
         assert t.bk == 256  # explicit override wins over the table
+        # decode rows pad up to one sublane tile, never clamp below it
+        t = pick_tiles(4, 50280, 768, variant="lowrank", backend="tpu")
+        assert t == TileConfig(8, 256, 64)
         t = pick_tiles(256, 256, 256, variant="inject_replay", backend="tpu")
         assert t == TileConfig(32, 128, 8)  # third-variant autotune entry
 

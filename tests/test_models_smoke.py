@@ -11,8 +11,8 @@ import pytest
 from repro.configs import ARCH_NAMES, get_reduced_config
 from repro.models import decode_step, forward, init_cache, init_params
 
-# Single-device smoke only — no meshes/shardings anywhere in these tests, so
-# they run on legacy jax too (pin() is a no-op without an ambient mesh).
+# Single-device smoke only — no meshes/shardings anywhere in these tests
+# (pin() is a no-op without an ambient mesh).
 
 ALL = ARCH_NAMES + ["amr-paper-100m"]
 

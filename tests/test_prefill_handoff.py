@@ -9,7 +9,7 @@ from repro.configs import get_reduced_config
 from repro.models import decode_step, forward, init_params
 from repro.models.model import _encoder_forward, prefill_with_cache
 
-# Single-device consistency checks — run on legacy jax too (no meshes).
+# Single-device consistency checks (no meshes).
 
 FAMILIES = ["gemma-2b", "mamba2-370m", "zamba2-1.2b", "gemma3-1b",
             "whisper-small", "dbrx-132b"]

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from _markers import requires_modern_jax
 from repro.configs import get_config
 from repro.launch import specs as specs_lib
 from repro.parallel import sharding as shard_lib
@@ -88,7 +87,6 @@ class TestParamRules:
                 == specs.opt.mu["layers"][0]["mlp"]["w_gate"])
 
 
-@requires_modern_jax
 class TestBatchAndCache:
     def test_batch_spec_divisible(self):
         assert shard_lib.batch_partition_spec(MESH, 256, 2) == P(("data",), None)
@@ -107,7 +105,6 @@ class TestBatchAndCache:
         assert kv_spec[1] == "data"  # batch dim
 
 
-@requires_modern_jax
 class TestConstraints:
     def test_pin_noop_without_mesh(self):
         from repro.parallel.constraints import pin

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from _markers import requires_modern_jax
 from repro.ckpt import CheckpointManager, restore_tree, save_tree
 from repro.ckpt.checkpoint import latest_step
 from repro.data import SyntheticLM
@@ -149,20 +148,19 @@ class TestFaultTolerance:
         assert res.steps_done == 6
 
 
-@requires_modern_jax
 class TestCompressedCollective:
     def test_quant_psum_single_axis(self):
         """int8-compressed psum matches exact within quantization error."""
         from repro.parallel.collectives import compressed_psum_tree
         mesh = jax.make_mesh((1,), ("dp",),
                              axis_types=(jax.sharding.AxisType.Auto,))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         g = {"w": jnp.linspace(-1.0, 1.0, 64).reshape(8, 8)}
-        f = shard_map(lambda t: compressed_psum_tree(t, "dp"), mesh=mesh,
-                      in_specs=(jax.tree.map(lambda _: P(), g),),
-                      out_specs=jax.tree.map(lambda _: P(), g), check_rep=False)
+        f = jax.shard_map(lambda t: compressed_psum_tree(t, "dp"), mesh=mesh,
+                          in_specs=(jax.tree.map(lambda _: P(), g),),
+                          out_specs=jax.tree.map(lambda _: P(), g),
+                          check_vma=False)
         out = f(g)
         np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                    atol=2.0 / 127)
