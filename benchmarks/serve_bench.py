@@ -84,9 +84,7 @@ def _serve(cfg, params, requests, n_slots, capacity, *, record_logits,
             engine.submit(Request(prompt=r.prompt, max_new_tokens=2))
         engine.run()
         engine.completions.clear()
-        engine.steps_done = 0
-        engine.decode_seconds = 0.0
-        engine.decode_tokens = 0
+        engine.counters.reset()
     for r in requests:
         engine.submit(Request(prompt=r.prompt, max_new_tokens=r.max_new_tokens,
                               eos_id=r.eos_id))
@@ -104,8 +102,8 @@ def _throughput_row(cfg, params, concurrency, gen, capacity, n_requests):
     total_tokens = sum(len(c.tokens) for c in done)
     complete = (len(done) == n_requests
                 and all(len(c.tokens) == gen for c in done))
-    steady = (engine.decode_tokens / engine.decode_seconds
-              if engine.decode_seconds > 0 else 0.0)
+    ctr = engine.counters
+    steady = ctr.decode_tokens / ctr.decode_seconds if ctr.decode_seconds > 0 else 0.0
     return {
         "kind": "throughput", "mode": cfg.numerics.mode,
         "concurrency": concurrency, "requests": n_requests,

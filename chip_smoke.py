@@ -345,7 +345,9 @@ def phase_serve(cfg, *, slots: int = 4, requests: int = 8,
                    f"{done[-1].tokens} != served alone {solo.tokens}")
             out[nm.mode] = {
                 "wall_s_with_compile": wall,
-                "decode_tok_s": busy.decode_tokens / max(busy.decode_seconds, 1e-9),
+                "decode_tok_s": (busy.counters.decode_tokens
+                                 / max(busy.counters.decode_seconds, 1e-9)),
+                "admit_s": busy.counters.admit_seconds,
                 "tokens_last": list(done[-1].tokens[:8]), "solo_match": True,
                 "peak": _peak_gib()}
     _say("serve", {"slots": slots, "requests": requests,

@@ -82,9 +82,7 @@ def main(argv=None) -> None:
             engine.submit(Request(prompt=prompts[0], max_new_tokens=2))
             engine.run()
             engine.completions.clear()
-            engine.steps_done = 0
-            engine.decode_seconds = 0.0
-            engine.decode_tokens = 0
+            engine.counters.reset()
 
         for p in prompts:
             engine.submit(Request(prompt=p, max_new_tokens=args.gen))
@@ -96,12 +94,13 @@ def main(argv=None) -> None:
     lat = sorted(c.total_s for c in done)
     print(f"[serve] {len(done)} requests, {total_tokens} tokens in {wall:.2f}s "
           f"({total_tokens / wall:.1f} tok/s end-to-end)")
-    if engine.decode_seconds > 0:
+    ctr = engine.counters
+    if ctr.decode_seconds > 0:
         # steady-state decode rate: tokens produced by masked decode steps
         # only (excludes queue wait + prefill + any compile)
-        print(f"[serve] steady-state decode: {engine.decode_tokens} tokens / "
-              f"{engine.decode_seconds:.2f}s = "
-              f"{engine.decode_tokens / engine.decode_seconds:.1f} tok/s")
+        print(f"[serve] steady-state decode: {ctr.decode_tokens} tokens / "
+              f"{ctr.decode_seconds:.2f}s = "
+              f"{ctr.decode_tokens / ctr.decode_seconds:.1f} tok/s")
     print(f"[serve] latency p50 {lat[len(lat) // 2] * 1e3:.0f}ms "
           f"max {lat[-1] * 1e3:.0f}ms; stats {engine.stats()}")
     print("[serve] sample:", list(done[0].tokens)[:16])

@@ -20,7 +20,7 @@ from repro.numerics import AMRNumerics, resolve_numerics
 from repro.numerics.approx_matmul import approx_matmul
 from repro.parallel.constraints import ambient_axis_size, pin
 
-from .layers import apply_rope, dense, init_rms_norm, rms_norm
+from .layers import apply_rope, dense, init_rms_norm, rms_norm, seam_scope
 
 NEG_INF = -2.0e38
 
@@ -83,14 +83,15 @@ def _gqa_scores(q, k, numerics=None):
     modes route through the seam at site ``attn.qk`` (resolved against a
     ``NumericsPolicy`` here, so per-layer assignments can pin it)."""
     numerics = resolve_numerics(numerics, "attn.qk")
-    if numerics is not None and not numerics.is_exact():
-        return _seam_scores(q, k, numerics)
-    B, S, Hq, D = q.shape
-    Hkv = k.shape[2]
-    g = Hq // Hkv
-    q = q.reshape(B, S, Hkv, g, D)
-    scores = jnp.einsum("bskgd,btkd->bkgst", q, k) / (D ** 0.5)
-    return scores.reshape(B, Hkv * g, S, k.shape[1])
+    with seam_scope("attn.qk"):
+        if numerics is not None and not numerics.is_exact():
+            return _seam_scores(q, k, numerics)
+        B, S, Hq, D = q.shape
+        Hkv = k.shape[2]
+        g = Hq // Hkv
+        q = q.reshape(B, S, Hkv, g, D)
+        scores = jnp.einsum("bskgd,btkd->bkgst", q, k) / (D ** 0.5)
+        return scores.reshape(B, Hkv * g, S, k.shape[1])
 
 
 def _seam_combine(probs, v, numerics: AMRNumerics):
@@ -111,14 +112,15 @@ def _seam_combine(probs, v, numerics: AMRNumerics):
 def _gqa_combine(probs, v, numerics=None):
     """probs: (B, Hq, S, T), v: (B,T,Hkv,D) -> (B,S,Hq,D)."""
     numerics = resolve_numerics(numerics, "attn.pv")
-    if numerics is not None and not numerics.is_exact():
-        return _seam_combine(probs, v, numerics)
-    B, Hq, S, T = probs.shape
-    Hkv = v.shape[2]
-    g = Hq // Hkv
-    probs = probs.reshape(B, Hkv, g, S, T)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
-    return out.reshape(B, S, Hq, v.shape[-1])
+    with seam_scope("attn.pv"):
+        if numerics is not None and not numerics.is_exact():
+            return _seam_combine(probs, v, numerics)
+        B, Hq, S, T = probs.shape
+        Hkv = v.shape[2]
+        g = Hq // Hkv
+        probs = probs.reshape(B, Hkv, g, S, T)
+        out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+        return out.reshape(B, S, Hq, v.shape[-1])
 
 
 def attend_full(
@@ -250,9 +252,10 @@ def attend_decode(
     # index on the model-sharded cache dim makes GSPMD replicate the whole
     # cache per layer ("involuntary full rematerialization"); the select is
     # elementwise — it shards, fuses, and aliases in place under donation
-    hit = (jnp.arange(C, dtype=jnp.int32)[None, :] == slot[:, None])[:, :, None, None]
-    new_k = jnp.where(hit, k.astype(cache.k.dtype), cache.k)
-    new_v = jnp.where(hit, v.astype(cache.v.dtype), cache.v)
+    with jax.named_scope("kv.write"):
+        hit = (jnp.arange(C, dtype=jnp.int32)[None, :] == slot[:, None])[:, :, None, None]
+        new_k = jnp.where(hit, k.astype(cache.k.dtype), cache.k)
+        new_v = jnp.where(hit, v.astype(cache.v.dtype), cache.v)
 
     scores = _gqa_scores(q, new_k, numerics).astype(jnp.float32)  # (B, Hq, 1, C)
     idx = jnp.arange(C)[None, :]
@@ -348,14 +351,15 @@ def attend_prefill(
     out = pin(dense(out, params["wo"], numerics, site="attn.wo"), "batch", None, None)
 
     C = capacity
-    if window > 0 and C <= S:
-        # ring layout: token t lives at slot t % C; the last C tokens survive
-        roll = S % C
-        k_c = jnp.roll(k[:, -C:], roll, axis=1)
-        v_c = jnp.roll(v[:, -C:], roll, axis=1)
-    else:
-        pad = C - S
-        k_c = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_c = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    cache = KVCache(k_c, v_c, jnp.asarray(S, jnp.int32))
+    with jax.named_scope("kv.write"):
+        if window > 0 and C <= S:
+            # ring layout: token t lives at slot t % C; the last C tokens survive
+            roll = S % C
+            k_c = jnp.roll(k[:, -C:], roll, axis=1)
+            v_c = jnp.roll(v[:, -C:], roll, axis=1)
+        else:
+            pad = C - S
+            k_c = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            v_c = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        cache = KVCache(k_c, v_c, jnp.asarray(S, jnp.int32))
     return out, cache

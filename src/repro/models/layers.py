@@ -6,6 +6,8 @@ dicts of jnp arrays; init functions mirror apply functions 1:1.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -14,6 +16,13 @@ from repro.numerics.approx_matmul import approx_matmul
 from repro.parallel.constraints import pin
 
 Numerics = AMRNumerics | NumericsPolicy | None
+
+
+def seam_scope(site: str | None):
+    """``jax.named_scope("seam." + site)``: the call site's label in the op
+    metadata of everything it lowers to, in every numerics mode (compile-time
+    metadata only; no value changes).  No label, no scope."""
+    return jax.named_scope("seam." + site) if site else contextlib.nullcontext()
 
 
 def dense(x: jnp.ndarray, w: jnp.ndarray, numerics: Numerics = None,
@@ -31,11 +40,12 @@ def dense(x: jnp.ndarray, w: jnp.ndarray, numerics: Numerics = None,
     multiplier design (numerics/policy.py).
     """
     numerics = resolve_numerics(numerics, site)
-    if numerics is None or numerics.is_exact():
-        return jnp.matmul(x, w)
-    shape = x.shape
-    out = approx_matmul(x.reshape(-1, shape[-1]), w, numerics, site=site)
-    return out.reshape(*shape[:-1], w.shape[-1]).astype(x.dtype)
+    with seam_scope(site):
+        if numerics is None or numerics.is_exact():
+            return jnp.matmul(x, w)
+        shape = x.shape
+        out = approx_matmul(x.reshape(-1, shape[-1]), w, numerics, site=site)
+        return out.reshape(*shape[:-1], w.shape[-1]).astype(x.dtype)
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:

@@ -443,7 +443,8 @@ def decode_step(cfg: ModelConfig, params: dict, token: jnp.ndarray, cache: Any,
         x, cache_all, g = carry
         group_params, _ = scanned
         gi = g if g_static is None else g_static
-        group_cache = jax.tree.map(lambda l: l[gi], cache_all)
+        with jax.named_scope("kv.carry"):
+            group_cache = jax.tree.map(lambda l: l[gi], cache_all)
         new_caches = []
         for i, kind in enumerate(kinds):
             lp = group_params[i]
@@ -457,9 +458,10 @@ def decode_step(cfg: ModelConfig, params: dict, token: jnp.ndarray, cache: Any,
                 x, c = _apply_layer_decode(cfg, lp, x, kind, group_cache[i], shared,
                                            ekv, numerics)
             new_caches.append(c)
-        cache_all = jax.tree.map(
-            lambda full, new: jax.lax.dynamic_update_index_in_dim(full, new, gi, 0),
-            cache_all, tuple(new_caches))
+        with jax.named_scope("kv.carry"):
+            cache_all = jax.tree.map(
+                lambda full, new: jax.lax.dynamic_update_index_in_dim(full, new, gi, 0),
+                cache_all, tuple(new_caches))
         return (x, cache_all, g + 1), None
 
     kinds2, n_repeat = group_structure(cfg)
@@ -477,7 +479,8 @@ def decode_step(cfg: ModelConfig, params: dict, token: jnp.ndarray, cache: Any,
             (params["layers"], jnp.arange(n_repeat)),
             unroll=n_repeat if cfg.unroll_layers else 1)
     if active is not None:
-        new_cache = _merge_active(cache, new_cache, active)
+        with jax.named_scope("kv.merge"):
+            new_cache = _merge_active(cache, new_cache, active)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     return unembed(x, head), new_cache

@@ -19,6 +19,7 @@ from repro.configs.base import MoEConfig
 from repro.numerics import AMRNumerics
 from repro.parallel.constraints import pin
 
+from .layers import seam_scope
 
 
 def init_moe(key, d_model: int, cfg: MoEConfig, dtype) -> dict:
@@ -107,10 +108,13 @@ def _moe_forward_global(
         hidden_pin = lambda t: pin(t, None, None, "tp")
         out_pin = lambda t: t
     if numerics is None or numerics.is_exact():
-        g = hidden_pin(jnp.einsum("ecd,edf->ecf", xbuf, params["w_gate"]))
-        u = hidden_pin(jnp.einsum("ecd,edf->ecf", xbuf, params["w_up"]))
+        with seam_scope("moe.expert.w_gate"):
+            g = hidden_pin(jnp.einsum("ecd,edf->ecf", xbuf, params["w_gate"]))
+        with seam_scope("moe.expert.w_up"):
+            u = hidden_pin(jnp.einsum("ecd,edf->ecf", xbuf, params["w_up"]))
         h = (jax.nn.silu(g) * u).astype(x.dtype)
-        ybuf = out_pin(jnp.einsum("ecf,efd->ecd", h, params["w_down"]))  # (E, C, D)
+        with seam_scope("moe.expert.w_down"):
+            ybuf = out_pin(jnp.einsum("ecf,efd->ecd", h, params["w_down"]))  # (E, C, D)
     else:
         from repro.numerics.approx_matmul import approx_matmul
 
@@ -122,7 +126,8 @@ def _moe_forward_global(
         # to the old per-expert vmap; amr_noise draws ONE (E, C, F) tensor,
         # so experts decorrelate without the unit-scope key plumbing.
         def expert_mm(a, w, site):
-            return approx_matmul(a, w, numerics, site=site).astype(x.dtype)
+            with seam_scope(site):
+                return approx_matmul(a, w, numerics, site=site).astype(x.dtype)
 
         g = hidden_pin(expert_mm(xbuf, params["w_gate"], "moe.expert.w_gate"))
         u = hidden_pin(expert_mm(xbuf, params["w_up"], "moe.expert.w_up"))
@@ -180,10 +185,13 @@ def _moe_local_body(xf, router, w_gate, w_up, w_down, cfg: MoEConfig,
 
     xbuf = jnp.zeros((E, C + 1, D), xf.dtype).at[fid_s, slot].set(
         xf[tok_s], mode="drop")[:, :C]
-    g = jnp.einsum("ecd,edf->ecf", xbuf, w_gate)
-    u = jnp.einsum("ecd,edf->ecf", xbuf, w_up)
+    with seam_scope("moe.expert.w_gate"):
+        g = jnp.einsum("ecd,edf->ecf", xbuf, w_gate)
+    with seam_scope("moe.expert.w_up"):
+        u = jnp.einsum("ecd,edf->ecf", xbuf, w_up)
     h = (jax.nn.silu(g) * u).astype(xf.dtype)
-    ybuf = jnp.einsum("ecf,efd->ecd", h, w_down)
+    with seam_scope("moe.expert.w_down"):
+        ybuf = jnp.einsum("ecf,efd->ecd", h, w_down)
 
     ypad = jnp.pad(ybuf, ((0, 0), (0, 1), (0, 0)))
     gathered = ypad[fid_s, slot] * (fw_s * keep)[:, None].astype(xf.dtype)

@@ -29,7 +29,7 @@ from repro.numerics import AMRNumerics, resolve_numerics
 from repro.numerics.approx_matmul import approx_matmul
 from repro.parallel.constraints import pin
 
-from .layers import dense, init_rms_norm, rms_norm
+from .layers import dense, init_rms_norm, rms_norm, seam_scope
 
 
 def ssm_dims(d_model: int, cfg: SSMConfig) -> dict:
@@ -143,14 +143,15 @@ def ssd_chunked(x, dt, a_log, b, c, chunk: int, return_state: bool = False,
     h_prev = jnp.moveaxis(h_prev, 0, 1)                        # (B,nc,H,N,P) state BEFORE chunk
 
     nm = resolve_numerics(numerics, "ssm.scan")
-    if nm is not None and not nm.is_exact():
-        # decay-weighted C panel against the carried state, grouped per
-        # (batch, chunk, head): (B,nc,H,Q,N) @ (B,nc,H,N,P) seam call
-        dc = (ch * jnp.exp(cum)[..., None]).transpose(0, 1, 3, 2, 4)
-        y_inter = approx_matmul(dc, h_prev, nm,
-                                site="ssm.scan").transpose(0, 1, 3, 2, 4)
-    else:
-        y_inter = jnp.einsum("bnthi,bnth,bnhip->bnthp", ch, jnp.exp(cum), h_prev)
+    with seam_scope("ssm.scan"):
+        if nm is not None and not nm.is_exact():
+            # decay-weighted C panel against the carried state, grouped per
+            # (batch, chunk, head): (B,nc,H,Q,N) @ (B,nc,H,N,P) seam call
+            dc = (ch * jnp.exp(cum)[..., None]).transpose(0, 1, 3, 2, 4)
+            y_inter = approx_matmul(dc, h_prev, nm,
+                                    site="ssm.scan").transpose(0, 1, 3, 2, 4)
+        else:
+            y_inter = jnp.einsum("bnthi,bnth,bnhip->bnthp", ch, jnp.exp(cum), h_prev)
     y = (y_intra + y_inter).reshape(B, S_pad, H, P)[:, :S]
     if return_state:
         # note: state axes are (H, N, P); SSMState stores (H, N, P) too
@@ -245,12 +246,13 @@ def ssm_decode(params: dict, xin: jnp.ndarray, state: SSMState, d_model: int,
     xdt = x * dt[..., None]                                    # (B,H,P)
     h_new = decay[..., None, None] * state.h + bh[..., None] * xdt[:, :, None, :]
     nm = resolve_numerics(numerics, "ssm.scan")
-    if nm is not None and not nm.is_exact():
-        # one-row state readout through the seam: (B,H,1,N) @ (B,H,N,P)
-        yss = approx_matmul(ch[:, :, None, :], h_new, nm,
-                            site="ssm.scan")[:, :, 0, :]
-    else:
-        yss = jnp.einsum("bhn,bhnp->bhp", ch, h_new)
+    with seam_scope("ssm.scan"):
+        if nm is not None and not nm.is_exact():
+            # one-row state readout through the seam: (B,H,1,N) @ (B,H,N,P)
+            yss = approx_matmul(ch[:, :, None, :], h_new, nm,
+                                site="ssm.scan")[:, :, 0, :]
+        else:
+            yss = jnp.einsum("bhn,bhnp->bhp", ch, h_new)
     y = yss + params["d_skip"][None, :, None] * x
     y = y.reshape(Bt, d_inner).astype(xin.dtype)
     y = y * jax.nn.silu(z)

@@ -31,9 +31,27 @@ queue/slot/step progress for external watchdogs, and a
 ``StragglerMonitor`` flags decode steps slower than the running median —
 a host-side stall (e.g. a paging device or a preempting neighbour) shows
 up as flagged steps rather than silent p99 inflation.
+
+Counters and spans: ``engine.counters`` (:class:`EngineCounters`) is the
+one record of the engine's work — decode steps, seconds and tokens,
+admissions with their host seconds, prefill tokens and compiles, queue
+wait; ``stats()`` and the heartbeat payload are built from it, and
+``counters.reset()`` zeroes it (after a warm-up).  Each phase is a host
+span (``runtime.spans.span``) that a running JAX profiler records on the
+device ops' clock:
+
+  * ``serve.admit`` (``uid``, ``slot``, ``prompt_len``), one per request,
+    holding ``serve.prefill`` (prompt upload and prefill dispatch;
+    ``compiled=1`` when the prefill compiled), ``serve.insert`` and
+    ``serve.first_token`` (the last logits' ``device_get`` and argmax);
+  * ``serve.decode`` (``step``, ``active``), one per decode step, holding
+    ``serve.decode.launch`` (building the step's inputs, dispatch),
+    ``serve.decode.sync`` (the blocking ``device_get``) and
+    ``serve.decode.emit`` (the per-slot bookkeeping, finishing, heartbeat).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from functools import partial
 from typing import Callable
@@ -45,6 +63,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import init_cache, prefill_with_cache
 from repro.runtime.fault import Heartbeat, StragglerMonitor
+from repro.runtime.spans import span
 from repro.train.steps import make_serve_step
 
 from .request import Completion, Request, RequestQueue
@@ -61,7 +80,29 @@ def _insert_request(engine_cache, request_cache, slot):
             r = jnp.expand_dims(r, 1)
         return jax.lax.dynamic_update_slice_in_dim(e, r.astype(e.dtype), slot, axis=1)
 
-    return jax.tree.map(one, engine_cache, request_cache)
+    with jax.named_scope("kv.write"):
+        return jax.tree.map(one, engine_cache, request_cache)
+
+
+@dataclasses.dataclass
+class EngineCounters:
+    """Running totals of the engine's work since creation or ``reset()``.
+
+    Seconds are host clock over work that ends in a ``device_get``, so they
+    include the device time the host waited for."""
+
+    decode_steps: int = 0
+    decode_seconds: float = 0.0    # masked decode steps, dispatch to fetch
+    decode_tokens: int = 0         # tokens produced by decode steps (not prefill)
+    admitted: int = 0
+    admit_seconds: float = 0.0     # admissions (``serve.admit``), to the first token
+    prefill_tokens: int = 0
+    prefill_compiles: int = 0
+    queue_wait_seconds: float = 0.0  # sum over admissions of t_admit - t_submit
+
+    def reset(self) -> None:
+        for f in dataclasses.fields(self):
+            setattr(self, f.name, f.default)
 
 
 class ServeEngine:
@@ -97,15 +138,21 @@ class ServeEngine:
         self._slot_toks: list[list[int]] = [[] for _ in range(n_slots)]
         self._slot_logits: list[list] = [[] for _ in range(n_slots)]
         self.completions: list[Completion] = []
-        self.steps_done = 0
-        self.decode_seconds = 0.0  # cumulative masked-decode-step wall time
-        self.decode_tokens = 0     # tokens produced by decode steps (not prefill)
+        self.counters = EngineCounters()
 
         self._prefill = jax.jit(
             partial(prefill_with_cache, cfg, capacity=capacity))
         self._decode = jax.jit(make_serve_step(cfg, with_logits=record_logits),
                                donate_argnums=(1,))
         self._insert = jax.jit(_insert_request, donate_argnums=(0,))
+
+    @property
+    def decode_seconds(self) -> float:
+        return self.counters.decode_seconds
+
+    @property
+    def decode_tokens(self) -> int:
+        return self.counters.decode_tokens
 
     # ------------------------------------------------------------- intake
     def submit(self, req: Request) -> int:
@@ -145,7 +192,8 @@ class ServeEngine:
         if self.heartbeat is None:
             return
         self.heartbeat.payload = {
-            "step": self.steps_done,
+            "step": self.counters.decode_steps,
+            "admitted": self.counters.admitted,
             "active_slots": int(self._active.sum()),
             "queued": len(self.queue),
             "completed": len(self.completions),
@@ -157,55 +205,74 @@ class ServeEngine:
 
     def _admit(self) -> None:
         """Admit queued requests into free slots, FIFO order."""
+        c = self.counters
         while self.queue and self.slots.n_free:
             req = self.queue.pop()
             slot = self.slots.allocate()
             assert slot is not None
             req.t_admit = time.monotonic()
-            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            logits, rcache = self._prefill(self.params, toks)
-            self.cache = self._insert(self.cache, rcache, jnp.int32(slot))
-            last = jax.device_get(logits[:, -1].astype(jnp.float32))[0]
-            first = int(np.argmax(last))
-            req.t_first_token = time.monotonic()
-            self._slot_req[slot] = req
-            self._slot_toks[slot] = [first]
-            self._slot_logits[slot] = [last] if self.record_logits else []
-            self._active[slot] = True
-            self._next_tok[slot] = first
-            self._maybe_finish(slot)
-            self._beat()
+            with span("serve.admit", uid=req.uid, slot=slot, prompt_len=len(req.prompt)):
+                with span("serve.prefill") as sp:
+                    n_compiled = self._prefill._cache_size()
+                    toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                    logits, rcache = self._prefill(self.params, toks)
+                    if self._prefill._cache_size() > n_compiled:
+                        c.prefill_compiles += 1
+                        sp.set_metadata(compiled=1)
+                with span("serve.insert"):
+                    self.cache = self._insert(self.cache, rcache, jnp.int32(slot))
+                with span("serve.first_token"):
+                    last = jax.device_get(logits[:, -1].astype(jnp.float32))[0]
+                    first = int(np.argmax(last))
+                req.t_first_token = time.monotonic()
+                self._slot_req[slot] = req
+                self._slot_toks[slot] = [first]
+                self._slot_logits[slot] = [last] if self.record_logits else []
+                self._active[slot] = True
+                self._next_tok[slot] = first
+                self._maybe_finish(slot)
+                self._beat()
+            c.admitted += 1
+            c.admit_seconds += time.monotonic() - req.t_admit
+            c.prefill_tokens += len(req.prompt)
+            c.queue_wait_seconds += req.t_admit - req.t_submit
 
     def _decode_once(self) -> None:
         """One masked decode step for every live slot."""
-        batch = {
-            "token": jnp.asarray(self._next_tok)[:, None],
-            "active": jnp.asarray(self._active),
-        }
-        t0 = time.monotonic()
-        out = self._decode(self.params, self.cache, batch)
-        if self.record_logits:
-            next_tok, last_logits, self.cache = out
-            logits_host = jax.device_get(last_logits)
-        else:
-            next_tok, self.cache = out
-            logits_host = None
-        tok_host = jax.device_get(next_tok)  # blocks: true step time
-        dt = time.monotonic() - t0
-        self.steps_done += 1
-        self.decode_seconds += dt
-        self.decode_tokens += int(self._active.sum())
-        if self.straggler.observe(self.steps_done, dt):
-            self._log(f"[serve] step {self.steps_done}: straggler "
-                      f"({dt * 1e3:.1f}ms vs median "
-                      f"{self.straggler.median() * 1e3:.1f}ms)")
-        for slot in np.flatnonzero(self._active):
-            self._slot_toks[slot].append(int(tok_host[slot]))
-            if logits_host is not None:
-                self._slot_logits[slot].append(np.asarray(logits_host[slot]))
-            self._next_tok[slot] = int(tok_host[slot])
-            self._maybe_finish(slot)
-        self._beat()
+        c = self.counters
+        n_active = int(self._active.sum())
+        with span("serve.decode", step=c.decode_steps + 1, active=n_active):
+            with span("serve.decode.launch"):
+                batch = {
+                    "token": jnp.asarray(self._next_tok)[:, None],
+                    "active": jnp.asarray(self._active),
+                }
+                t0 = time.monotonic()
+                out = self._decode(self.params, self.cache, batch)
+            with span("serve.decode.sync"):
+                if self.record_logits:
+                    next_tok, last_logits, self.cache = out
+                    logits_host = jax.device_get(last_logits)
+                else:
+                    next_tok, self.cache = out
+                    logits_host = None
+                tok_host = jax.device_get(next_tok)  # blocks: true step time
+                dt = time.monotonic() - t0
+            with span("serve.decode.emit"):
+                c.decode_steps += 1
+                c.decode_seconds += dt
+                c.decode_tokens += n_active
+                if self.straggler.observe(c.decode_steps, dt):
+                    self._log(f"[serve] step {c.decode_steps}: straggler "
+                              f"({dt * 1e3:.1f}ms vs median "
+                              f"{self.straggler.median() * 1e3:.1f}ms)")
+                for slot in np.flatnonzero(self._active):
+                    self._slot_toks[slot].append(int(tok_host[slot]))
+                    if logits_host is not None:
+                        self._slot_logits[slot].append(np.asarray(logits_host[slot]))
+                    self._next_tok[slot] = int(tok_host[slot])
+                    self._maybe_finish(slot)
+                self._beat()
 
     # ------------------------------------------------------------ finish
     def _maybe_finish(self, slot: int) -> None:
@@ -233,9 +300,9 @@ class ServeEngine:
     # ------------------------------------------------------------- stats
     def stats(self) -> dict:
         return {
-            "steps": self.steps_done,
             "completed": len(self.completions),
             "active_slots": int(self._active.sum()),
             "queued": len(self.queue),
             "stragglers": len(self.straggler.flagged),
+            **dataclasses.asdict(self.counters),
         }

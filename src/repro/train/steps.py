@@ -99,7 +99,8 @@ def make_train_step(cfg: ModelConfig, *, peak_lr: float = 3e-4, warmup: int = 10
                                         state.step)
 
         lr = cosine_warmup(state.step, peak_lr=peak_lr, warmup=warmup, total=total_steps)
-        params, opt = adamw_update(grads, state.opt, state.params, lr)
+        with jax.named_scope("optim.update"):
+            params, opt = adamw_update(grads, state.opt, state.params, lr)
         metrics = {"loss": loss, "aux": aux, "lr": lr}
         return TrainState(params, opt, state.step + 1), metrics
 

@@ -145,16 +145,31 @@ class TestServeEngine:
         assert all(c.finish_reason == "length" for c in done)
 
     def test_eos_finishes_early(self, exact_setup):
+        """EOS stops the stream right after its first occurrence, at a
+        position past the first token (a token first seen at position 0
+        would test only an immediate stop)."""
         cfg, params = exact_setup
+        rng = np.random.default_rng(0)
+        candidates = list(PROMPTS) + [
+            tuple(int(t) for t in rng.integers(0, cfg.vocab, n)) for n in (3, 5, 7, 9) * 4]
         eng = ServeEngine(cfg, params, n_slots=1, capacity=CAP)
-        eng.submit(Request(prompt=PROMPTS[0], max_new_tokens=8))
-        [ref] = eng.run()
-        eos = ref.tokens[2]  # force EOS at the third generated token
+        for p in candidates:
+            eng.submit(Request(prompt=p, max_new_tokens=8))
+        pick = None
+        for ref in eng.run():
+            later = [k for k, t in enumerate(ref.tokens) if k and t not in ref.tokens[:k]]
+            if later:
+                pick = (ref, later[0])
+                break
+        assert pick is not None, (
+            "no candidate prompt's reference stream has a token first seen "
+            "after position 0; add prompts")
+        ref, k = pick
         eng2 = ServeEngine(cfg, params, n_slots=1, capacity=CAP)
-        eng2.submit(Request(prompt=PROMPTS[0], max_new_tokens=8, eos_id=eos))
+        eng2.submit(Request(prompt=ref.prompt, max_new_tokens=8, eos_id=ref.tokens[k]))
         [done] = eng2.run()
         assert done.finish_reason == "eos"
-        assert done.tokens == ref.tokens[:3]
+        assert done.tokens == ref.tokens[:k + 1]
 
     def test_no_recompile_across_admit_evict_patterns(self, exact_setup):
         """The masked decode step traces ONCE no matter which slots are live."""
@@ -177,9 +192,39 @@ class TestServeEngine:
         payload = json.loads((tmp_path / "hb.json").read_text())
         assert payload["completed"] == len(done)
         assert payload["queued"] == 0 and payload["active_slots"] == 0
-        assert payload["step"] == eng.steps_done
+        assert payload["step"] == eng.counters.decode_steps
         # every decode step was observed by the straggler monitor
-        assert len(mon.times) == min(eng.steps_done, 10)
+        assert len(mon.times) == min(eng.counters.decode_steps, 10)
+
+    def test_counters_count_exactly_and_reset(self, exact_setup):
+        """2 slots, 3 requests of 3, 2 and 4 tokens: A and B admitted; step 1
+        finishes B; C admitted; step 2 finishes A; steps 3-4 finish C."""
+        cfg, params = exact_setup
+        eng = ServeEngine(cfg, params, n_slots=2, capacity=CAP)
+        prompts, gens = PROMPTS[:3], (3, 2, 4)
+        for p, g in zip(prompts, gens):
+            eng.submit(Request(prompt=p, max_new_tokens=g))
+        eng.run()
+        c = eng.counters
+        assert c.admitted == 3
+        assert c.prefill_tokens == sum(len(p) for p in prompts)
+        assert c.prefill_compiles == len({len(p) for p in prompts})
+        assert c.decode_tokens == sum(g - 1 for g in gens)  # first tokens come from prefill
+        assert c.decode_steps == 4
+        assert c.admit_seconds > 0 and c.decode_seconds > 0 and c.queue_wait_seconds >= 0
+        assert (eng.decode_seconds, eng.decode_tokens) == (c.decode_seconds, c.decode_tokens)
+        st = eng.stats()
+        assert st["decode_steps"] == 4 and st["admitted"] == 3
+        c.reset()
+        assert all(getattr(c, f) == 0 for f in ("decode_steps", "decode_seconds",
+                                                "decode_tokens", "admitted",
+                                                "admit_seconds", "prefill_tokens",
+                                                "prefill_compiles", "queue_wait_seconds"))
+        assert eng.decode_seconds == 0 and eng.decode_tokens == 0
+        # the same lengths again: the prefill compiles no more
+        eng.submit(Request(prompt=PROMPTS[0], max_new_tokens=2))
+        eng.run()
+        assert (c.admitted, c.prefill_compiles, c.decode_steps, c.decode_tokens) == (1, 0, 1, 1)
 
 
 # ------------------------------------------------- batched-vs-solo exactness
